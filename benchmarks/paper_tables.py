@@ -19,6 +19,7 @@ import numpy as np
 
 from benchmarks.common import (SIZES, build_index, dataset, emit,
                                fixed_batches, time_batches)
+from repro.api.compile_cache import enable_persistent_cache
 from repro.data.strings import make_workload
 
 DATASET_NAMES = ("dblp", "usps", "sprot")
@@ -226,6 +227,7 @@ def main() -> None:
                          "per kind next to the paper's 160-200 B target; "
                          "none reproduces the paper table verbatim")
     args = ap.parse_args()
+    enable_persistent_cache()
     for key, (title, fn) in ALL.items():
         if args.only and key != args.only:
             continue

@@ -19,6 +19,9 @@ def main() -> None:
 
     from benchmarks import beyond_paper, paper_tables, roofline, substrates
     from benchmarks.common import SCALE
+    from repro.api.compile_cache import enable_persistent_cache
+
+    enable_persistent_cache()
 
     suites = dict(paper_tables.ALL)
     suites.update(beyond_paper.ALL)

@@ -29,6 +29,7 @@ import time
 import jax
 
 from benchmarks.common import build_index, dataset, emit
+from repro.api.compile_cache import enable_persistent_cache
 from repro.data.strings import make_keystroke_events
 from repro.launch.serve import _replay_batched, _replay_sequential
 from repro.serving import BatchStats, CompletionService
@@ -162,6 +163,7 @@ def main() -> None:
     ap.add_argument("--block", type=int, default=16)
     ap.add_argument("--repeats", type=int, default=3)
     args = ap.parse_args()
+    enable_persistent_cache()
 
     rows = bench_serving(smoke=args.smoke, sessions=args.sessions,
                          block=args.block, repeats=args.repeats)

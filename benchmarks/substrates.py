@@ -46,6 +46,7 @@ import jax
 
 from benchmarks.common import (SIZES, build_index, dataset, emit,
                                fixed_batches, time_batches)
+from repro.api.compile_cache import enable_persistent_cache
 from repro.data.strings import make_workload
 
 # a fixed VMEM budget sized between the packed and uncompressed resident
@@ -180,6 +181,7 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=256)
     ap.add_argument("--k", type=int, default=10)
     args = ap.parse_args()
+    enable_persistent_cache()
 
     rows = bench_substrates(k=args.k, batch=args.batch, smoke=args.smoke)
     _table(rows)

@@ -6,12 +6,42 @@ batch sizes drift.  Here shapes are first *bucketed* (batch and query
 length rounded up to powers of two) so nearby shapes share an executable,
 and the executables live in an LRU with a fixed capacity so a long-lived
 serving process cannot accumulate compilations without bound.
+
+:func:`enable_persistent_cache` is the one place that points JAX's
+on-disk compilation cache somewhere, so a fresh process reuses the
+executables an earlier one compiled.
 """
 
 from __future__ import annotations
 
+import os
 from collections import OrderedDict
+from pathlib import Path
 from typing import Callable, Hashable
+
+import jax
+
+#: The persistent cache's directory when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: one fixed, git-ignored path in the checkout.  A cache directory
+#: that moved between runs (a temp, pid or time-based path) would never
+#: be hit again.
+REPO_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    If ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads that
+    directory and this sets no other; otherwise the cache goes to
+    :data:`REPO_CACHE_DIR`.  Entry points call it once at start-up
+    (``repro.launch.serve``, ``chip_smoke.py``, the benchmarks); library
+    code never does, so importing the package leaves JAX's configuration
+    alone."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(REPO_CACHE_DIR))
+    return str(REPO_CACHE_DIR)
 
 
 def bucket_size(n: int, minimum: int = 8) -> int:

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import warnings
 from dataclasses import dataclass, replace
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -159,9 +160,9 @@ class CompletionIndex:
         build would, and folds the changes into ``EngineConfig`` — which
         keys every jit/compile-cache entry, so stale executables can
         never be hit while ones for the old configuration stay cached.
-        Any actual change bumps :attr:`epoch`: compiled sessions hold
-        closures over the old config and re-derive their state at the
-        next keystroke boundary, exactly like a hot-swap.
+        Any actual change bumps :attr:`epoch`: compiled sessions are
+        bound to the old config and re-derive their state at the next
+        keystroke boundary, exactly like a hot-swap.
 
         Build-time fields (:data:`BUILD_FIELDS`) are rejected — rebuild
         via ``build_index`` or fold them into the next :meth:`compact`.
@@ -346,10 +347,10 @@ class CompletionIndex:
             self, prepared: PreparedCompaction) -> "CompletionIndex":
         """Hot-swap a prepared compaction in place (cheap, synchronous).
 
-        Adopts the fresh structures, drops the compile cache — its
-        closures captured the old epoch's device tables under keys that
-        do not name the epoch — and bumps :attr:`epoch` so live sessions
-        re-derive their state at the next keystroke boundary.  Mutations
+        Adopts the fresh structures, drops the compile cache — the fresh
+        tables have new shapes, so the old executables would never be
+        hit again — and bumps :attr:`epoch` so live sessions re-derive
+        their state at the next keystroke boundary.  Mutations
         that landed after the snapshot survive: they are diffed against
         it and re-applied as a new overlay on the fresh base."""
         current = self.live_items()
@@ -399,34 +400,37 @@ class CompletionIndex:
 
     # -- lookup ------------------------------------------------------------
 
+    # Every jitted entry point takes the DeviceTrie as its first argument
+    # and the getters bind the current tables with ``partial``: a closure
+    # over the tables would embed them in each program as HLO constants
+    # (gigabytes at a million strings) and key the compile cache on the
+    # data.  The cached executables depend only on shapes and ``cfg``.
+
     def _fn(self, batch: int, length: int, k: int, cfg: eng.EngineConfig):
         key = ("batch", batch, length, k, cfg)
 
         def factory():
-            dev = self.device
+            return jax.jit(
+                lambda dev, qs, qlens: eng.complete_batch(dev, cfg, qs,
+                                                          qlens, k))
 
-            @jax.jit
-            def run(qs, qlens):
-                return eng.complete_batch(dev, cfg, qs, qlens, k)
-
-            return run
-
-        return self._compile_cache.get(key, factory)
+        return partial(self._compile_cache.get(key, factory), self.device)
 
     def _session_fns(self, k: int):
         """(init, advance-one-char, topk) jitted for this index's cfg."""
         key = ("session", k, self.cfg)
 
         def factory():
-            dev, cfg = self.device, self.cfg
-            init = jax.jit(lambda: eng.init_locus_state(dev, cfg))
-            adv = jax.jit(
-                lambda state, c: eng.advance_locus_state(dev, cfg, state, c))
+            cfg = self.cfg
+            init = jax.jit(lambda dev: eng.init_locus_state(dev, cfg))
+            adv = jax.jit(lambda dev, state, c:
+                          eng.advance_locus_state(dev, cfg, state, c))
             topk = jax.jit(
-                lambda state: eng.topk_from_loci(dev, cfg, state, k))
+                lambda dev, state: eng.topk_from_loci(dev, cfg, state, k))
             return init, adv, topk
 
-        return self._compile_cache.get(key, factory)
+        return tuple(partial(f, self.device)
+                     for f in self._compile_cache.get(key, factory))
 
     def _slab_fns(self, block: int):
         """(init, advance) jitted at the fixed ``[block]`` lane shape.
@@ -439,10 +443,10 @@ class CompletionIndex:
         key = ("slab", block, self.cfg)
 
         def factory():
-            dev, cfg = self.device, self.cfg
-            init = jax.jit(lambda: eng.init_locus_batch(dev, cfg, block))
+            cfg = self.cfg
+            init = jax.jit(lambda dev: eng.init_locus_batch(dev, cfg, block))
 
-            def _advance(slab, chars, resets):
+            def _advance(dev, slab, chars, resets):
                 fresh = eng.init_locus_state(dev, cfg)
                 slab = jax.tree.map(
                     lambda s, z: jnp.where(
@@ -453,20 +457,21 @@ class CompletionIndex:
 
             # the slab is threaded flush-to-flush and never read after the
             # advance, so donating it lets XLA update lanes in place
-            return init, jax.jit(_advance, donate_argnums=0)
+            return init, jax.jit(_advance, donate_argnums=1)
 
-        return self._compile_cache.get(key, factory)
+        return tuple(partial(f, self.device)
+                     for f in self._compile_cache.get(key, factory))
 
     def _slab_topk_fn(self, block: int, k: int):
         """Batched top-k over a state slab, jitted per (block, k)."""
         key = ("slab_topk", block, k, self.cfg)
 
         def factory():
-            dev, cfg = self.device, self.cfg
+            cfg = self.cfg
             return jax.jit(
-                lambda slab: eng.topk_from_loci_batch(dev, cfg, slab, k))
+                lambda dev, slab: eng.topk_from_loci_batch(dev, cfg, slab, k))
 
-        return self._compile_cache.get(key, factory)
+        return partial(self._compile_cache.get(key, factory), self.device)
 
     def session(self, k: int = 10):
         """Open a stateful incremental-typing session (see

@@ -49,7 +49,7 @@ class Session:
         """Migrate onto the index's current epoch.
 
         After a hot-swap (``compact``) or ``reconfigure`` the compiled
-        fns hold closures over the previous epoch's tables/config, so
+        fns are bound to the previous epoch's tables/config, so
         refetch them and re-derive the whole per-char state history by
         replaying the retained prefix — the keystroke-boundary migration
         the epoch versioning exists for."""

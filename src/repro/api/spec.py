@@ -30,9 +30,11 @@ class IndexSpec:
     cache_k: materialize per-node top-K lists (0 = off; beyond-paper).
     frontier/gens/expand/max_steps: static engine widths (jit shape key).
     substrate: execution substrate — "jnp" (reference), "pallas" (tuned
-        kernels; interpret mode off-TPU), or "auto" (pallas on TPU, jnp
-        elsewhere).  Resolved at build/load time against the substrate
-        registry in :mod:`repro.core.engine.substrate`.
+        kernels; interpret mode off-TPU, not yet compilable for a TPU),
+        or "auto" (jnp on every backend, see
+        :func:`~repro.core.engine.substrate.resolve_substrate`).
+        Resolved at build/load time against the substrate registry in
+        :mod:`repro.core.engine.substrate`.
     memory_budget: VMEM bytes the pallas substrate may spend keeping
         tables resident; tries whose tables exceed it run the
         DMA-streamed kernel tier (HBM-resident tables) instead of

@@ -13,13 +13,12 @@ so on the pallas substrate the [S*k]-candidate reduction runs the fused
 top-k selection kernel instead of a host-side concat-and-sort.  Two
 execution paths share that merge:
 
-- :func:`sharded_complete`: ``jax.shard_map`` over a device mesh (needs
-  the modern sharding APIs; feature-gated by ``HAS_MODERN_SHARDING``);
+- :func:`sharded_complete`: ``jax.shard_map`` over a device mesh — every
+  index constructed with a ``mesh`` answers here;
 - :meth:`ShardedCompletionIndex._complete_local`: a single-process path
   that answers every shard from the stacked trie and fuses the merge in
-  one jitted dispatch — the serving shape for one host carrying many
-  shards, and the path that keeps the sharded index fully exercised on
-  jax builds without ``shard_map`` (construct with ``mesh=None``).
+  one jitted dispatch — the serving shape for one device carrying many
+  shards (construct with ``mesh=None``).
 """
 
 from __future__ import annotations
@@ -38,18 +37,6 @@ from repro.api import CompletionIndex, IndexSpec, build_index
 from repro.api.compile_cache import CompileCache, bucket_size
 from repro.core import engine as eng
 
-# Feature detection: the manual-sharding APIs this module (and the mesh
-# tests) rely on moved to the jax top level in newer releases.  Tests
-# skip on the flag instead of CI hard-deselecting them.
-from repro.distributed.sharding import missing_sharding_apis
-
-_MISSING_SHARDING_APIS = missing_sharding_apis()
-HAS_MODERN_SHARDING = not _MISSING_SHARDING_APIS
-SHARDING_SKIP_REASON = (
-    "container jax lacks " + ", ".join(_MISSING_SHARDING_APIS)
-    + " (simulated-mesh paths need a newer jax)"
-) if _MISSING_SHARDING_APIS else ""
-
 
 class UnsupportedOnShardedIndex(NotImplementedError):
     """An operation that needs a local :class:`CompletionIndex` was called
@@ -58,13 +45,6 @@ class UnsupportedOnShardedIndex(NotImplementedError):
     Raised instead of a bare ``NotImplementedError`` so callers can catch
     the *category* — per-keystroke sessions, mutation/compaction — and
     the message always names the local-mode alternative."""
-
-
-def require_modern_sharding() -> None:
-    """Raise a clear error (instead of an AttributeError mid-trace) when
-    the running jax cannot execute the shard_map paths."""
-    if not HAS_MODERN_SHARDING:
-        raise RuntimeError(SHARDING_SKIP_REASON)
 
 
 def shard_strings(strings, scores, n_shards: int):
@@ -186,7 +166,6 @@ def sharded_complete(stacked: eng.DeviceTrie, cfg: eng.EngineConfig,
     qs: int32[B, L] global batch; qlens int32[B].
     Returns (scores[B, k], global_sids[B, k]).
     """
-    require_modern_sharding()
     sub = eng.get_substrate(cfg.substrate)
     trie_spec = jax.tree.map(lambda _: P(model_axis), stacked,
                              is_leaf=lambda x: not isinstance(x, tuple))
@@ -260,7 +239,7 @@ class ShardedCompletionIndex:
             put = jnp.asarray  # local mode: whole stacked trie on one device
         self.device_tries = jax.tree.map(
             put, stacked, is_leaf=lambda x: isinstance(x, np.ndarray))
-        self._local_cache = CompileCache(maxsize=16)
+        self._compile_cache = CompileCache(maxsize=16)
 
     @classmethod
     def from_shards(cls, shards, *, mesh=None, model_axis="model",
@@ -330,7 +309,20 @@ class ShardedCompletionIndex:
 
             return jax.jit(run)
 
-        return self._local_cache.get(key, factory)
+        return self._compile_cache.get(key, factory)
+
+    def _mesh_fn(self, B: int, L: int, k: int):
+        """Jitted :func:`sharded_complete` over this index's mesh, one
+        executable per (batch, length, k), LRU-cached like local mode."""
+        key = ("mesh", B, L, k, self.cfg)
+
+        def factory():
+            cfg, mesh, stride = self.cfg, self.mesh, self.stride
+            return jax.jit(lambda trie, qs, qlens: sharded_complete(
+                trie, cfg, qs, qlens, k, mesh=mesh, sid_stride=stride,
+                data_axes=self.data_axes, model_axis=self.model_axis))
+
+        return self._compile_cache.get(key, factory)
 
     def _complete_local(self, qs: np.ndarray, qlens: np.ndarray, k: int,
                         n_real: int):
@@ -351,12 +343,10 @@ class ShardedCompletionIndex:
         max_len = max((len(q) for q in queries), default=1)
         L = max(8, 1 << (max_len - 1).bit_length())
         qs, qlens = pad_queries(queries, L)
-        if self.mesh is not None and HAS_MODERN_SHARDING:
-            scores, gsids = sharded_complete(
-                self.device_tries, self.cfg, jnp.asarray(qs),
-                jnp.asarray(qlens), k, mesh=self.mesh,
-                sid_stride=self.stride, data_axes=self.data_axes,
-                model_axis=self.model_axis)
+        if self.mesh is not None:
+            fn = self._mesh_fn(qs.shape[0], qs.shape[1], k)
+            scores, gsids = fn(self.device_tries, jnp.asarray(qs),
+                               jnp.asarray(qlens))
         else:
             scores, gsids = self._complete_local(
                 np.asarray(qs), np.asarray(qlens), k, len(queries))

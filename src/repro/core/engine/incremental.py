@@ -18,7 +18,7 @@ from repro.core.engine import packed as pk
 from repro.core.engine.locus import (decode_states, dict_child_window,
                                      encode_states, expand_frontier,
                                      finalize_loci, link_lookup)
-from repro.core.engine.primitives import iters_for, resolve_sub
+from repro.core.engine.primitives import iters_for, note_path, resolve_sub
 from repro.core.engine.structs import DeviceTrie, EngineConfig, NEG_ONE
 
 
@@ -64,6 +64,7 @@ def advance_locus_state(t: DeviceTrie, cfg: EngineConfig, state: LocusState,
     instead of rescanning the prefix.
     """
     sub = resolve_sub(cfg, sub)
+    note_path("advance", "jnp")   # no substrate has a kernel for it
     F = cfg.frontier
     E = cfg.edit_budget
     H = state.rows.shape[0]

@@ -10,12 +10,38 @@ has tuned code for.
 
 from __future__ import annotations
 
+import contextlib
 import math
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.engine.structs import INT_MAX, NEG_ONE
+
+
+# Open dispatch records (``record_dispatch``); ``note_path`` adds to each.
+_RECORDS: list[set[str]] = []
+
+
+def note_path(op: str, path: str) -> None:
+    """Record that dispatch ``op`` took ``path`` (e.g. "walk", "jnp" or
+    "beam", "pallas-streamed").  Called while a program is traced."""
+    for rec in _RECORDS:
+        rec.add(f"{op}={path}")
+
+
+@contextlib.contextmanager
+def record_dispatch():
+    """Collect, as "op=path" strings, the substrate dispatches of every
+    program traced inside the block.  A program already compiled is not
+    traced again, so the record names the code of the programs this
+    block built."""
+    rec: set[str] = set()
+    _RECORDS.append(rec)
+    try:
+        yield rec
+    finally:
+        _RECORDS[:] = [r for r in _RECORDS if r is not rec]
 
 
 def resolve_sub(cfg, sub):

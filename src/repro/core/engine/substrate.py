@@ -22,8 +22,9 @@ kernel :func:`repro.kernels.ops.beam_topk`, cached merges through
 :func:`repro.kernels.ops.topk_select` / ``cached_topk_merge``, and runs
 in interpret mode off-TPU.  ``EngineConfig.substrate`` names the
 substrate, so it rides every jit/compile-cache key;
-``resolve_substrate("auto")`` picks ``pallas`` on TPU and ``jnp``
-elsewhere (interpret-mode pallas is opt-in, not a default, off-TPU).
+``resolve_substrate("auto")`` picks ``jnp`` on every backend, because it
+is the only substrate whose whole path compiles for a TPU (see
+:func:`resolve_substrate`).
 
 With the fused beam kernel every hot phase — walk, beam, cached merge —
 is substrate-pluggable.  Each fused kernel additionally runs in one of
@@ -74,6 +75,7 @@ class Substrate:
                    qlens: jax.Array):
         """qs int32[B, L] (-1 padded), qlens int32[B] ->
         (loci[B, F], overflow[B])."""
+        primitives.note_path("walk", "jnp")
         return jax.vmap(
             lambda q, ql: locus.locus_dp(t, cfg, q, ql, self))(qs, qlens)
 
@@ -83,6 +85,7 @@ class Substrate:
                           k: int):
         """scores/payload int32[B, C] -> (top_s[B, k], top_p[B, k]),
         score-descending, ties toward the lower candidate index."""
+        primitives.note_path("top-k", "jnp")
         top_s, idx = jax.lax.top_k(scores, k)
         return top_s, jnp.take_along_axis(payload, idx, axis=1)
 
@@ -92,6 +95,7 @@ class Substrate:
         (scores[B, k], sids[B, k], exact[B])."""
         assert cfg.use_cache and k <= cfg.cache_k, \
             "cache disabled or k too large"
+        primitives.note_path("cached-merge", "jnp")
         flat_s, flat_i = cached.gather_cached(t, loci)
         s, p = self.topk_with_payload(flat_s, flat_i, k)
         return s, p, jnp.ones(loci.shape[:-1], bool)
@@ -107,6 +111,7 @@ class Substrate:
                         loci: jax.Array, k: int):
         """Beam phase 2 over a locus batch: (scores[B,k], sids[B,k],
         exact[B])."""
+        primitives.note_path("beam", "jnp")
         return jax.vmap(lambda l: beam.beam_topk(t, cfg, l, k))(loci)
 
 
@@ -147,8 +152,10 @@ class PallasSubstrate(Substrate):
     # larger ones the DMA-streamed tier
     _DEFAULT_VMEM_BUDGET = 8 << 20
 
-    # physical per-core VMEM; a user-set memory_budget is clamped here —
-    # a larger budget would declare tables "resident" that can never fit
+    # Mosaic's default scoped-VMEM limit per kernel (not the chip's
+    # physical VMEM, which is larger on a v5e); a user-set memory_budget
+    # is clamped here — a larger budget would declare tables "resident"
+    # that a kernel under the default limit can never hold
     _VMEM_BYTES = 16 << 20
 
     # the DMA-streamed tier stages [lanes, tile] windows in VMEM scratch,
@@ -319,6 +326,7 @@ class PallasSubstrate(Substrate):
         variant = self.walk_variant(t, cfg, int(qs.shape[1]))
         if variant is None:
             return super().walk_batch(t, cfg, qs, qlens)
+        primitives.note_path("walk", f"pallas-{variant}")
         streamed = variant == "streamed"
         if pk.is_packed(t):
             return ops.locus_walk(t, cfg, qs, qlens, streamed=streamed)
@@ -374,12 +382,14 @@ class PallasSubstrate(Substrate):
             return super().beam_topk_batch(t, cfg, loci, k)
         from repro.kernels import ops
 
+        primitives.note_path("beam", f"pallas-{variant}")
         return ops.beam_topk(t, cfg, loci, k,
                              streamed=variant == "streamed")
 
     def topk_with_payload(self, scores, payload, k):
         from repro.kernels import ops
 
+        primitives.note_path("top-k", "pallas")
         return ops.topk_select(scores, payload, k)
 
     def cached_topk_batch(self, t, cfg, loci, k):
@@ -395,6 +405,7 @@ class PallasSubstrate(Substrate):
             return super().cached_topk_batch(t, cfg, loci, k)
         from repro.kernels import ops
 
+        primitives.note_path("cached-merge", "pallas-resident")
         exact = jnp.ones(loci.shape[:-1], bool)
         if pk.is_packed(t):
             # quantized cache: translate loci to chain-representative
@@ -444,13 +455,18 @@ def available_substrates() -> list[str]:
 def resolve_substrate(name: str) -> str:
     """Resolve a user-facing substrate choice to a registry name.
 
-    ``"auto"`` picks ``pallas`` when running on TPU and the ``jnp``
-    reference elsewhere (interpret-mode pallas off-TPU is opt-in by naming
-    ``"pallas"`` explicitly).  Concrete names are validated against the
-    registry and passed through.
+    ``"auto"`` picks ``jnp`` on every backend: it is the only substrate
+    whose whole path compiles for a TPU.  The Pallas kernels still read
+    their tables with per-lane gathers (and the locus DP compacts with a
+    scatter), which the TPU's Mosaic compiler does not lower;
+    ``tests/test_tpu_compile.py`` holds each refusal as a strict xfail.
+    So ``"pallas"`` is opt-in by name: on a TPU it raises the compiler's
+    own error, elsewhere it runs the kernels in interpret mode.
+    Concrete names are validated against the registry and passed
+    through.
     """
     if name == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+        return "jnp"
     get_substrate(name)
     return name
 

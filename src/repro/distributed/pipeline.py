@@ -27,16 +27,6 @@ from repro.distributed import sharding as sh
 from repro.models import layers as L
 from repro.models import transformer as tf
 
-# Feature detection mirroring repro.core.distributed: the pipelined loss
-# needs top-level jax.shard_map (and meshes built with
-# jax.sharding.AxisType); on older jax the tests skip on this flag.
-_MISSING_SHARDING_APIS = sh.missing_sharding_apis()
-HAS_MODERN_SHARDING = not _MISSING_SHARDING_APIS
-SHARDING_SKIP_REASON = (
-    "container jax lacks " + ", ".join(_MISSING_SHARDING_APIS)
-    + " (pipeline parallelism needs a newer jax)"
-) if _MISSING_SHARDING_APIS else ""
-
 
 def stack_stages(params, n_stages: int):
     """Reshape layer-stacked leaves [L, ...] -> [n_stages, L/n_stages, ...]."""
@@ -63,8 +53,6 @@ def make_pp_loss_fn(cfg: tf.TransformerConfig, n_micro: int):
     (stack_stages); batch as usual {tokens,targets,mask} [B, S]."""
 
     def loss_fn(params, batch, _cfg=None):
-        if not HAS_MODERN_SHARDING:
-            raise RuntimeError(SHARDING_SKIP_REASON)
         mesh = sh.current_mesh()
         assert mesh is not None and "pod" in mesh.axis_names, \
             "pipeline mode needs a mesh with a 'pod' axis"

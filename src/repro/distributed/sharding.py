@@ -18,19 +18,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-def missing_sharding_apis() -> list[str]:
-    """Manual-sharding APIs the shard_map paths need but older jax
-    releases only ship under experimental spellings.  Shared by the
-    feature-detection flags in repro.core.distributed and
-    repro.distributed.pipeline (tests skip on them)."""
-    return [
-        name for name, ok in [
-            ("jax.shard_map", hasattr(jax, "shard_map")),
-            ("jax.sharding.AxisType", hasattr(jax.sharding, "AxisType")),
-        ] if not ok
-    ]
-
-
 LOGICAL_RULES: dict[str, tuple[str, ...]] = {
     "batch": ("pod", "data"),
     "edges": ("pod", "data", "model"),   # GNN full-graph edge lists

@@ -258,13 +258,13 @@ def _search(tabs, loci,
     gb_ref[...] = gb
     ls_ref[...] = jnp.full((bq, k), _NEG_ONE, jnp.int32)
     li_ref[...] = jnp.full((bq, k), _NEG_ONE, jnp.int32)
-    dm_ref[...] = jnp.full((bq,), _NEG_ONE, jnp.int32)
+    dm_ref[:, 0] = jnp.full((bq,), _NEG_ONE, jnp.int32)
 
     iota_w = jax.lax.broadcasted_iota(jnp.int32, (bq, W), 1)
 
     def step(_, carry):
         gn, gc, gb = gn_ref[...], gc_ref[...], gb_ref[...]
-        ls, li, dm = ls_ref[...], li_ref[...], dm_ref[...]
+        ls, li, dm = ls_ref[...], li_ref[...], dm_ref[:, 0]
         best = jnp.max(gb, axis=1)
         kth = ls[:, k - 1]
         # the reference while_loop's continuation condition, per query
@@ -318,12 +318,12 @@ def _search(tabs, loci,
         gb_ref[...] = jnp.where(m, keep_b, gb)
         ls_ref[...] = jnp.where(m, ls2, ls)
         li_ref[...] = jnp.where(m, li2, li)
-        dm_ref[...] = jnp.where(active, dm2, dm)
+        dm_ref[:, 0] = jnp.where(active, dm2, dm)
         return carry
 
     jax.lax.fori_loop(0, max_steps, step, 0)
 
-    gb, ls, dm = gb_ref[...], ls_ref[...], dm_ref[...]
+    gb, ls, dm = gb_ref[...], ls_ref[...], dm_ref[:, 0]
     best = jnp.max(gb, axis=1)
     kth = ls[:, k - 1]
     finished = ~((best >= 0) & (kth < best))
@@ -333,7 +333,7 @@ def _search(tabs, loci,
     exact = (dm <= kth) & finished
     os_ref[...] = ls
     oi_ref[...] = li_ref[...]
-    oe_ref[...] = exact.astype(jnp.int32)
+    oe_ref[:, 0] = exact.astype(jnp.int32)
 
 
 def _kernel(eptr_ref, enode_ref, escore_ref, eleaf_ref, lsid_ref,
@@ -378,23 +378,26 @@ def _kernel_streamed(eptr_hbm, enode_hbm, escore_hbm, eleaf_hbm, lsid_hbm,
 
 def _call(kernel, tables, table_specs, loci, scratch, *, k: int,
           gens: int, block_b: int, interpret: bool):
+    # the exact flag and the dropped-max tracker ride as [B, 1] columns:
+    # Mosaic tiles a rank-1 block only in multiples of 128 rows, a
+    # [block_b, 1] column whenever block_b is a multiple of 8 (or the
+    # whole batch)
     bsz, f = loci.shape
-    grid = (bsz // block_b,)
-    return pl.pallas_call(
+    s, i, e = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bsz // block_b,),
         in_specs=table_specs + [
             pl.BlockSpec((block_b, f), lambda i: (i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((block_b, k), lambda i: (i, 0)),
             pl.BlockSpec((block_b, k), lambda i: (i, 0)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
+            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bsz, k), jnp.int32),
             jax.ShapeDtypeStruct((bsz, k), jnp.int32),
-            jax.ShapeDtypeStruct((bsz,), jnp.int32),
+            jax.ShapeDtypeStruct((bsz, 1), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_b, gens), jnp.int32),   # gn: generator nodes
@@ -402,17 +405,18 @@ def _call(kernel, tables, table_specs, loci, scratch, *, k: int,
             pltpu.VMEM((block_b, gens), jnp.int32),   # gb: admissible bounds
             pltpu.VMEM((block_b, k), jnp.int32),      # ls: result scores
             pltpu.VMEM((block_b, k), jnp.int32),      # li: result sids
-            pltpu.VMEM((block_b,), jnp.int32),        # dropped_max tracker
+            pltpu.VMEM((block_b, 1), jnp.int32),      # dropped_max tracker
         ] + scratch,
         interpret=interpret,
     )(*tables, loci)
+    return s, i, e[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=(
     "gens", "expand", "k", "max_steps", "block_b", "interpret"))
 def beam_topk_batch(emit_ptr, emit_node, emit_score, emit_is_leaf, leaf_sid,
                     loci, *, gens: int, expand: int, k: int, max_steps: int,
-                    block_b: int = 8, interpret: bool = True):
+                    block_b: int = 8, interpret: bool):
     """Fused beam phase 2 over a locus batch (VMEM-resident tables).
 
     loci int32[B, F] (-1 padded locus antichains, B divisible by block_b;
@@ -440,7 +444,7 @@ def beam_topk_batch(emit_ptr, emit_node, emit_score, emit_is_leaf, leaf_sid,
 def beam_topk_batch_packed(p_flags, c_ids, c_eptr, c_enode, c_escore,
                            c_eleaf, c_maxscore, l_ids, l_sid, loci, *,
                            gens: int, expand: int, k: int, max_steps: int,
-                           block_b: int = 8, interpret: bool = True):
+                           block_b: int = 8, interpret: bool):
     """Compressed-layout variant of :func:`beam_topk_batch`: same
     contract and bit-identical results, reading the packed emission store
     (u8 flags, sorted ``c_ids`` side tables, u16-or-i32 scores/sids)
@@ -463,7 +467,7 @@ def beam_topk_batch_packed(p_flags, c_ids, c_eptr, c_enode, c_escore,
 def beam_topk_batch_streamed(emit_ptr, emit_node, emit_score, emit_is_leaf,
                              leaf_sid, loci, *, gens: int, expand: int,
                              k: int, max_steps: int, emit_tile: int,
-                             block_b: int = 4, interpret: bool = True):
+                             block_b: int = 8, interpret: bool):
     """HBM-resident variant of :func:`beam_topk_batch`: same contract,
     same results, but the emission tables stay in HBM and every step's
     pointer pairs / emission-row windows / sid gathers are

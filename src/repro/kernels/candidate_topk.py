@@ -49,7 +49,7 @@ def _kernel(q_ref, c_ref, os_ref, oi_ref, *, k: int, block_c: int):
 
 @functools.partial(jax.jit, static_argnames=("k", "block_c", "interpret"))
 def candidate_topk(query, candidates, k: int, *, block_c: int = 1024,
-                   interpret: bool = True):
+                   interpret: bool):
     """query float[D]; candidates float[C, D] (C divisible by block_c).
 
     Returns (scores[k] float32, ids[k] int32), score-descending.

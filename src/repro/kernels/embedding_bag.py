@@ -39,7 +39,7 @@ def _kernel(tab_ref, idx_ref, w_ref, out_ref, *, mode: str):
 @functools.partial(jax.jit,
                    static_argnames=("mode", "block_b", "interpret"))
 def embedding_bag_dense(table, idx, weights, *, mode: str = "sum",
-                        block_b: int = 128, interpret: bool = True):
+                        block_b: int = 128, interpret: bool):
     """table float[V, D]; idx int32[B, MB] (-1 pad); weights float[B, MB]."""
     bsz, mb = idx.shape
     v, d = table.shape

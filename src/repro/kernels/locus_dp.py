@@ -612,7 +612,7 @@ def _sweep(tabs, rfc, rec, rechild, rterm, q, qlen,
     covered = ((tin_j <= tin_i) & (tin_i < to[:, None, :]) & (ii != jj)
                & (tin_j >= 0) & (tin_i >= 0)).any(axis=2)
     loci_ref[...] = jnp.where(covered, _NEG_ONE, row)
-    ov_ref[...] = overflow
+    ov_ref[:, 0] = overflow
 
 
 def _kernel(fc_ref, ec_ref, echild_ref,
@@ -628,7 +628,7 @@ def _kernel(fc_ref, ec_ref, echild_ref,
         syn_mask_ref[...], tout_ref[...], tele_ref[...],
         lptr_ref[...], lrule_ref[...], ltgt_ref[...])
     _sweep(tabs, rfc_ref[...], rec_ref[...], rechild_ref[...], rterm_ref[...],
-           q_ref[...], qlen_ref[...], loci_ref, ov_ref, **statics)
+           q_ref[...], qlen_ref[:, 0], loci_ref, ov_ref, **statics)
 
 
 def _kernel_streamed(fc_hbm, ec_hbm, echild_hbm,
@@ -659,7 +659,7 @@ def _kernel_streamed(fc_hbm, ec_hbm, echild_hbm,
         StreamTable(ltgt_hbm, w2_buf, sem_2, link_tile),
         walk_iters, link_iters)
     _sweep(tabs, rfc_ref[...], rec_ref[...], rechild_ref[...], rterm_ref[...],
-           q_ref[...], qlen_ref[...], loci_ref, ov_ref, **statics)
+           q_ref[...], qlen_ref[:, 0], loci_ref, ov_ref, **statics)
 
 
 def _kernel_packed(lbl_ref, flg_ref, c_ids_ref, c_tout_ref,
@@ -677,7 +677,7 @@ def _kernel_packed(lbl_ref, flg_ref, c_ids_ref, c_tout_ref,
         sb_child_ref[...], t_ids_ref[...], tele_ref[...],
         la_ids_ref[...], la_ptr_ref[...], lrule_ref[...], ltgt_ref[...])
     _sweep(tabs, rfc_ref[...], rec_ref[...], rechild_ref[...], rterm_ref[...],
-           q_ref[...], qlen_ref[...], loci_ref, ov_ref, **statics)
+           q_ref[...], qlen_ref[:, 0], loci_ref, ov_ref, **statics)
 
 
 def _kernel_packed_streamed(lbl_hbm, flg_hbm, c_ids_ref, c_tout_ref,
@@ -698,31 +698,30 @@ def _kernel_packed_streamed(lbl_hbm, flg_hbm, c_ids_ref, c_tout_ref,
         sb_child_ref[...], t_ids_ref[...], tele_ref[...],
         la_ids_ref[...], la_ptr_ref[...], lrule_ref[...], ltgt_ref[...])
     _sweep(tabs, rfc_ref[...], rec_ref[...], rechild_ref[...], rterm_ref[...],
-           q_ref[...], qlen_ref[...], loci_ref, ov_ref, **statics)
+           q_ref[...], qlen_ref[:, 0], loci_ref, ov_ref, **statics)
 
 
 def _call(kernel, tables, table_specs, queries, qlens, scratch, *,
           frontier: int, block_q: int, interpret: bool):
+    # qlens / overflow ride as [B, 1] columns: Mosaic tiles a rank-1
+    # block only in multiples of 128 rows, a [block_q, 1] column
+    # whenever block_q is a multiple of 8 (or the whole batch)
     bsz, seq_len = queries.shape
-    grid = (bsz // block_q,)
-    return pl.pallas_call(
+    col = pl.BlockSpec((block_q, 1), lambda i: (i, 0))
+    loci, overflow = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bsz // block_q,),
         in_specs=table_specs + [
-            pl.BlockSpec((block_q, seq_len), lambda i: (i, 0)),
-            pl.BlockSpec((block_q,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_q, frontier), lambda i: (i, 0)),
-            pl.BlockSpec((block_q,), lambda i: (i,)),
-        ],
+            pl.BlockSpec((block_q, seq_len), lambda i: (i, 0)), col],
+        out_specs=[pl.BlockSpec((block_q, frontier), lambda i: (i, 0)), col],
         out_shape=[
             jax.ShapeDtypeStruct((bsz, frontier), jnp.int32),
-            jax.ShapeDtypeStruct((bsz,), jnp.int32),
+            jax.ShapeDtypeStruct((bsz, 1), jnp.int32),
         ],
         scratch_shapes=scratch,
         interpret=interpret,
-    )(*tables, queries, qlens)
+    )(*tables, queries, qlens.reshape(bsz, 1))
+    return loci, overflow[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -739,7 +738,7 @@ def locus_dp_walk(first_child, edge_char, edge_child,
                   max_terms: int, has_syn: bool, has_tele: bool,
                   has_links: bool, edit_budget: int = 0,
                   branch_width: int = 1, block_q: int = 8,
-                  interpret: bool = True):
+                  interpret: bool):
     """Fused locus DP over a query batch (VMEM-resident tables).
 
     queries int32[B, L] (-1 padded, B divisible by block_q; the wrapper in
@@ -782,8 +781,8 @@ def locus_dp_walk_streamed(first_child, edge_char, edge_child,
                            has_tele: bool, has_links: bool,
                            edit_budget: int = 0, branch_width: int = 1,
                            walk_tile: int = 8,
-                           link_tile: int = 8, block_q: int = 4,
-                           interpret: bool = True):
+                           link_tile: int = 8, block_q: int = 8,
+                           interpret: bool):
     """HBM-resident variant of :func:`locus_dp_walk`: same contract, same
     results, but the dictionary-sized tables stay in HBM and every access
     is a double-buffered windowed DMA.  ``walk_tile``/``link_tile`` are
@@ -840,7 +839,7 @@ def locus_dp_walk_packed(p_labels, p_flags, c_ids, c_tout,
                          max_terms: int, has_syn: bool, has_tele: bool,
                          has_links: bool, edit_budget: int = 0,
                          branch_width: int = 1, block_q: int = 8,
-                         interpret: bool = True):
+                         interpret: bool):
     """Fused locus DP over the compressed (packed) layout, every table
     VMEM-resident.  Same contract and bit-identical results as
     :func:`locus_dp_walk`; the table set is the packed one — u8
@@ -879,8 +878,8 @@ def locus_dp_walk_packed_streamed(p_labels, p_flags, c_ids, c_tout,
                                   max_lhs_len: int, max_terms: int,
                                   has_syn: bool, has_tele: bool,
                                   has_links: bool, edit_budget: int = 0,
-                                  branch_width: int = 1, block_q: int = 4,
-                                  interpret: bool = True):
+                                  branch_width: int = 1, block_q: int = 8,
+                                  interpret: bool):
     """HBM-resident variant of :func:`locus_dp_walk_packed`: only the two
     N-sized u8 planes (labels/flags) stay in HBM and stream per access as
     width-1 windows through their own u8 staging buffers; the sparse side

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_NEG = -(2**31 - 1)
+from repro.kernels.topk_select import select_topk
 
 
 def _kernel(loci_ref, ts_ref, ti_ref, os_ref, op_ref, *, k: int):
@@ -41,17 +41,12 @@ def _kernel(loci_ref, ts_ref, ti_ref, os_ref, op_ref, *, k: int):
     mask = jnp.repeat(valid, kk, axis=1)          # loci-major, K-minor
     sc = jnp.where(mask, sc, -1)                  # -1 = empty (as in jnp)
     si = jnp.where(mask, si, -1)
-    rows = jnp.arange(bb)
-    for j in range(k):
-        best = jnp.argmax(sc, axis=1)             # ties: first maximum
-        os_ref[:, j] = sc[rows, best]
-        op_ref[:, j] = si[rows, best]
-        sc = sc.at[rows, best].set(_NEG)
+    os_ref[...], op_ref[...] = select_topk(sc, si, k)   # ties: first max
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_b", "interpret"))
 def locus_topk_merge(loci, topk_score, topk_sid, k: int, *, block_b: int = 8,
-                     interpret: bool = True):
+                     interpret: bool):
     """loci int32[B, F] (-1 padded, B divisible by block_b; wrapper in
     ops.py pads); topk_score/topk_sid int32[N, K] ->
     (scores[B, k], sids[B, k]), score-descending, -1 where empty."""

@@ -121,14 +121,12 @@ def locus_walk(t, cfg, queries, qlens, block_q: int = 8,
     cfg: EngineConfig.  queries int32[B, L] (-1 padded), qlens int32[B].
     Returns (loci[B, F], overflow[B]) matching the jnp reference DP
     bit-for-bit.  ``streamed=True`` keeps the dictionary-sized tables in
-    HBM and streams windows per access (same results, smaller block).
+    HBM and streams windows per access (same results).
     """
     from repro.kernels.locus_dp import locus_dp_walk as _locus_dp
     from repro.kernels.locus_dp import \
         locus_dp_walk_streamed as _locus_dp_streamed
 
-    if streamed:
-        block_q = min(4, block_q)
     block_q = min(block_q, max(int(queries.shape[0]), 1))
     q, ql, b = _pad_query_batch(queries, qlens, block_q)
     if _is_packed(t):
@@ -196,7 +194,7 @@ def beam_topk(t, cfg, loci, k: int, block_b: int = 8,
     Returns (scores[B, k], sids[B, k], exact[B] bool) matching
     ``jax.vmap(engine.beam.beam_topk)`` bit-for-bit.  ``streamed=True``
     keeps the emission tables in HBM and streams row windows per step
-    (same results, smaller block).
+    (same results).
     """
     from repro.kernels.beam_topk import beam_topk_batch as _beam_topk
     from repro.kernels.beam_topk import \
@@ -228,8 +226,6 @@ def beam_topk(t, cfg, loci, k: int, block_b: int = 8,
             k=k, max_steps=cfg.max_steps, block_b=block_b,
             interpret=_interpret())
         return s[:b], i[:b], e[:b].astype(bool)
-    if streamed:
-        block_b = min(4, block_b)
     block_b = min(block_b, max(B, 1))
     # padded rows are all -1 loci => dead pool, -1 results, exact; sliced off
     l, b = _pad_rows(loci, block_b, -1)

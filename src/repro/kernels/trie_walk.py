@@ -47,7 +47,7 @@ def _kernel(fc_ref, ec_ref, echild_ref, q_ref, qlen_ref, node_ref, depth_ref,
     ec = ec_ref[...]
     echild = echild_ref[...]
     q = q_ref[...]
-    qlen = qlen_ref[...]
+    qlen = qlen_ref[:, 0]
     bq = q.shape[0]
     e = ec.shape[0]
 
@@ -73,13 +73,33 @@ def _kernel(fc_ref, ec_ref, echild_ref, q_ref, qlen_ref, node_ref, depth_ref,
 
     node0 = jnp.zeros((bq,), jnp.int32)
     node, matched = jax.lax.fori_loop(0, seq_len, step, (node0, node0))
-    node_ref[...] = node
-    depth_ref[...] = matched
+    node_ref[:, 0] = node
+    depth_ref[:, 0] = matched
+
+
+def _walk_call(kernel, tables, table_specs, queries, qlens, scratch, *,
+               block_q: int, interpret: bool):
+    # qlens / node / depth ride as [B, 1] columns: Mosaic tiles a rank-1
+    # block only in multiples of 128 rows, a [block_q, 1] column
+    # whenever block_q is a multiple of 8 (or the whole batch)
+    bsz, seq_len = queries.shape
+    col = pl.BlockSpec((block_q, 1), lambda i: (i, 0))
+    node, depth = pl.pallas_call(
+        kernel,
+        grid=(bsz // block_q,),
+        in_specs=table_specs + [
+            pl.BlockSpec((block_q, seq_len), lambda i: (i, 0)), col],
+        out_specs=[col, col],
+        out_shape=[jax.ShapeDtypeStruct((bsz, 1), jnp.int32)] * 2,
+        scratch_shapes=scratch,
+        interpret=interpret,
+    )(*tables, queries, qlens.reshape(bsz, 1))
+    return node[:, 0], depth[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "interpret"))
 def trie_walk(first_child, edge_char, edge_child, queries, qlens,
-              *, block_q: int = 128, interpret: bool = True):
+              *, block_q: int = 128, interpret: bool):
     """Deepest locus node + matched depth for each query.
 
     queries: int32[B, L] (-1 padded), B divisible by block_q (wrapper in
@@ -91,28 +111,13 @@ def trie_walk(first_child, edge_char, edge_child, queries, qlens,
     iters = max(1, (e).bit_length())
     if edge_char.shape[0] == 0:
         return jnp.zeros((bsz,), jnp.int32), jnp.zeros((bsz,), jnp.int32)
-    grid = (bsz // block_q,)
     kernel = functools.partial(_kernel, iters=iters, seq_len=seq_len)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n1,), lambda i: (0,)),
-            pl.BlockSpec((e,), lambda i: (0,)),
-            pl.BlockSpec((e,), lambda i: (0,)),
-            pl.BlockSpec((block_q, seq_len), lambda i: (i, 0)),
-            pl.BlockSpec((block_q,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_q,), lambda i: (i,)),
-            pl.BlockSpec((block_q,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bsz,), jnp.int32),
-            jax.ShapeDtypeStruct((bsz,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(first_child, edge_char, edge_child, queries, qlens)
+    table_specs = [pl.BlockSpec((n1,), lambda i: (0,)),
+                   pl.BlockSpec((e,), lambda i: (0,)),
+                   pl.BlockSpec((e,), lambda i: (0,))]
+    return _walk_call(kernel, [first_child, edge_char, edge_child],
+                      table_specs, queries, qlens, [], block_q=block_q,
+                      interpret=interpret)
 
 
 def _stream_kernel(fc_hbm, ec_hbm, echild_hbm, q_ref, qlen_ref,
@@ -120,7 +125,7 @@ def _stream_kernel(fc_hbm, ec_hbm, echild_hbm, q_ref, qlen_ref,
                    pair_buf, wc_buf, wk_buf, sem_p, sem_c, sem_k, *,
                    tile: int, iters: int, seq_len: int):
     q = q_ref[...]
-    qlen = qlen_ref[...]
+    qlen = qlen_ref[:, 0]
     bq = q.shape[0]
     fc_t = StreamTable(fc_hbm, pair_buf, sem_p, 2)
     ec_t = StreamTable(ec_hbm, wc_buf, sem_c, tile)
@@ -140,14 +145,13 @@ def _stream_kernel(fc_hbm, ec_hbm, echild_hbm, q_ref, qlen_ref,
 
     node0 = jnp.zeros((bq,), jnp.int32)
     node, matched = jax.lax.fori_loop(0, seq_len, step, (node0, node0))
-    node_ref[...] = node
-    depth_ref[...] = matched
+    node_ref[:, 0] = node
+    depth_ref[:, 0] = matched
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "block_q", "interpret"))
 def trie_walk_streamed(first_child, edge_char, edge_child, queries, qlens,
-                       *, tile: int, block_q: int = 8,
-                       interpret: bool = True):
+                       *, tile: int, block_q: int = 8, interpret: bool):
     """HBM-resident variant of :func:`trie_walk`: same contract, same
     results, but the CSR tables stay in HBM and each step's pointer pairs
     and child-row windows are DMA-streamed into VMEM scratch.  ``tile``
@@ -157,31 +161,17 @@ def trie_walk_streamed(first_child, edge_char, edge_child, queries, qlens,
     if edge_char.shape[0] == 0:
         return jnp.zeros((bsz,), jnp.int32), jnp.zeros((bsz,), jnp.int32)
     iters = max(1, tile.bit_length())
-    grid = (bsz // block_q,)
     kernel = functools.partial(_stream_kernel, tile=tile, iters=iters,
                                seq_len=seq_len)
     hbm = pl.BlockSpec(memory_space=pltpu.ANY)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[hbm, hbm, hbm,
-                  pl.BlockSpec((block_q, seq_len), lambda i: (i, 0)),
-                  pl.BlockSpec((block_q,), lambda i: (i,))],
-        out_specs=[
-            pl.BlockSpec((block_q,), lambda i: (i,)),
-            pl.BlockSpec((block_q,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bsz,), jnp.int32),
-            jax.ShapeDtypeStruct((bsz,), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 2), jnp.int32),     # pointer-pair stage
-            pltpu.VMEM((block_q, tile), jnp.int32),  # char-row windows
-            pltpu.VMEM((block_q, tile), jnp.int32),  # child-row windows
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        interpret=interpret,
-    )(first_child, edge_char, edge_child, queries, qlens)
+    scratch = [
+        pltpu.VMEM((block_q, 2), jnp.int32),     # pointer-pair stage
+        pltpu.VMEM((block_q, tile), jnp.int32),  # char-row windows
+        pltpu.VMEM((block_q, tile), jnp.int32),  # child-row windows
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.SemaphoreType.DMA((2,)),
+    ]
+    return _walk_call(kernel, [first_child, edge_char, edge_child],
+                      [hbm] * 3, queries, qlens, scratch, block_q=block_q,
+                      interpret=interpret)

@@ -9,7 +9,7 @@ For autocomplete archs this is the paper's end-to-end system: build (or
 incremental per-keystroke stream through stateful sessions — and report
 latency/throughput (Fig. 7-style numbers).  ``--substrate`` picks the
 execution substrate (jnp reference vs Pallas kernels; ``auto`` resolves
-to pallas on TPU only).
+to jnp, the only substrate that compiles for a TPU today).
 """
 
 from __future__ import annotations
@@ -22,6 +22,7 @@ import jax
 import numpy as np
 
 from repro.api import IndexSpec, build_index
+from repro.api.compile_cache import enable_persistent_cache
 from repro.core import CompletionIndex, make_rules
 from repro.configs import all_archs
 from repro.data.strings import DATASETS, make_keystroke_events, make_workload
@@ -370,8 +371,9 @@ def main():
                          "--load-index (the container records it)")
     ap.add_argument("--substrate", default=None,
                     choices=["jnp", "pallas", "auto"],
-                    help="execution substrate; auto = pallas on TPU, jnp "
-                         "elsewhere (interpret-mode pallas is opt-in). "
+                    help="execution substrate; auto = jnp on every "
+                         "backend (pallas is opt-in: interpret mode "
+                         "off-TPU, not yet compilable on a TPU). "
                          "Default: auto when building, the saved choice "
                          "when --load-index")
     ap.add_argument("--memory-budget", type=int, default=None,
@@ -416,6 +418,7 @@ def main():
                     help="restore an index instead of rebuilding")
     ap.add_argument("--smoke", action="store_true", default=True)
     args = ap.parse_args()
+    enable_persistent_cache()
 
     spec = all_archs()[args.arch]
     if spec.family == "autocomplete":

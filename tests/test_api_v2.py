@@ -3,7 +3,10 @@ round-trips, incremental Session-vs-batch equivalence, and the bounded
 compile cache."""
 
 import dataclasses
+import os
+import re
 
+import jax
 import numpy as np
 import pytest
 
@@ -283,3 +286,47 @@ def test_index_compile_cache_buckets_batches(paper_example):
     idx.complete(["a", "an", "and", "andy"], k=3)       # B=4 -> bucket 4: hit
     assert idx._compile_cache.misses == misses0 + 1
     assert idx._compile_cache.hits >= 1
+
+
+def test_batch_program_does_not_embed_the_index():
+    """The jitted batch entry point takes the tables as arguments: two
+    indexes of one config but different sizes lower to the same program
+    text up to the shape numbers.  A closure over the tables would embed
+    them as constants, growing the program with the dictionary.  (The
+    binary searches unroll log2(table size) rounds, so both sizes keep
+    every table within one power of two.)"""
+    idxs = []
+    for n in (500, 600):
+        ds = make_usps(n=n, seed=0)
+        idxs.append(build_index(ds.strings, ds.scores, make_rules(ds.rules),
+                                IndexSpec(kind="et")))
+    assert idxs[0].cfg == idxs[1].cfg
+    assert idxs[0].device.first_child.shape != idxs[1].device.first_child.shape
+    qs, qlens = np.full((8, 16), -1, np.int32), np.zeros((8,), np.int32)
+    texts = []
+    for idx in idxs:
+        run = idx._fn(8, 16, 10, idx.cfg)
+        texts.append(run.func.lower(*run.args, qs, qlens).as_text())
+    shapeless = [re.sub(r"\d+", "N", t) for t in texts]
+    assert shapeless[0] == shapeless[1]
+    assert len(texts[0]) < 200_000
+
+
+def test_persistent_cache_dir_honours_env(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set; without it
+    the cache goes to the one fixed directory inside the checkout."""
+    from repro.api import compile_cache as cc
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert cc.enable_persistent_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = cc.enable_persistent_cache()
+        assert path == str(cc.REPO_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == path
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -8,14 +8,6 @@ import sys
 
 import pytest
 
-from repro.core.distributed import HAS_MODERN_SHARDING, SHARDING_SKIP_REASON
-
-# every test here builds an AxisType mesh / traces through shard_map in its
-# subprocess (same interpreter + jax as this process), so skip them all on
-# old jax with the feature-detected reason instead of CI deselection
-pytestmark = pytest.mark.skipif(not HAS_MODERN_SHARDING,
-                                reason=SHARDING_SKIP_REASON)
-
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -50,6 +42,10 @@ got = idx.complete(qs, k=5)
 for q, row in zip(qs, got):
     exp = [s for s, _ in oracle.complete(q, 5)]
     assert [s for s, _ in row] == exp, (q, row, exp)
+# the mesh program is compiled once per batch shape, not once per call
+misses = idx._compile_cache.misses
+assert idx.complete(qs, k=5) == got
+assert idx._compile_cache.misses == misses
 print("OK")
 """
     assert "OK" in run_subprocess(code)

@@ -10,7 +10,6 @@ cached locus gather+merge) must reproduce the reference engine exactly.
 import numpy as np
 import pytest
 
-import jax
 
 from repro.api import IndexSpec, Session, build_index
 from repro.core import engine as eng
@@ -48,8 +47,9 @@ def test_registry_has_both_substrates():
 
 
 def test_auto_resolves_by_backend():
-    expect = "pallas" if jax.default_backend() == "tpu" else "jnp"
-    assert eng.resolve_substrate("auto") == expect
+    # jnp on every backend: the pallas kernels do not compile for a TPU
+    # yet (tests/test_tpu_compile.py), so auto never picks them
+    assert eng.resolve_substrate("auto") == "jnp"
     assert eng.resolve_substrate("pallas") == "pallas"
     with pytest.raises(ValueError, match="unknown substrate"):
         eng.resolve_substrate("nope")
@@ -239,6 +239,33 @@ def test_fused_walk_probe_envelope_falls_back(paper_data):
     # widths is claimed
     assert sub.can_walk_batch(_build(paper_data, "ht").device,
                               _build(paper_data, "ht").cfg, 16)
+
+
+@pytest.mark.parametrize("substrate,want", [
+    ("jnp", ["beam=jnp", "walk=jnp"]),
+    ("pallas", ["beam=pallas-resident", "walk=pallas-resident"]),
+])
+def test_traced_paths_record_the_dispatch(paper_data, substrate, want):
+    """The dispatch record names the code of the programs traced inside
+    it; a probe that declines on pallas shows up as the jnp code it fell
+    back to."""
+    from repro.core.engine.primitives import record_dispatch
+
+    idx = _build(paper_data, "et", substrate=substrate)
+    with record_dispatch() as paths:
+        idx.complete(["andy pa"], k=3)
+    assert sorted(paths) == want
+    with record_dispatch() as paths:
+        idx.complete(["andy pa"], k=3)   # compiled already: not traced
+        Session(idx, k=3).type("a")
+    assert sorted(paths) == ["advance=jnp"] + want[:1]
+    if substrate == "pallas":
+        sub = eng.get_substrate("pallas")
+        wide = _build(paper_data, "et", substrate="pallas",
+                      gens=2 * sub._BEAM_MAX_GENS)
+        with record_dispatch() as paths:
+            wide.complete(["andy pa"], k=3)
+        assert sorted(paths) == ["beam=jnp", "walk=pallas-resident"]
 
 
 def test_fused_walk_session_and_batch_agree(paper_data):

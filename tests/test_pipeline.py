@@ -1,14 +1,9 @@
 """Pipeline parallelism (GPipe over the `pod` axis): exactness vs the
 non-pipelined loss, and gradient flow through every stage."""
 
-import pytest
-
-from repro.distributed.pipeline import (HAS_MODERN_SHARDING,
-                                        SHARDING_SKIP_REASON)
 from tests.test_distributed import run_subprocess
 
 
-@pytest.mark.skipif(not HAS_MODERN_SHARDING, reason=SHARDING_SKIP_REASON)
 def test_pp_loss_matches_plain_and_grads_flow():
     code = """
 import jax, jax.numpy as jnp, numpy as np

@@ -100,10 +100,10 @@ def test_local_batch_bucketing_reuses_compiles(corpus):
     idx = ShardedCompletionIndex(strings, scores, rules, n_shards=2,
                                  kind="et")
     idx.complete(["an", "re", "w"], k=5)        # B=3 -> bucket 4
-    misses0 = idx._local_cache.misses
+    misses0 = idx._compile_cache.misses
     idx.complete(["andy", "bill", "rec", "en"], k=5)   # B=4: same bucket
-    assert idx._local_cache.misses == misses0
-    assert idx._local_cache.hits >= 1
+    assert idx._compile_cache.misses == misses0
+    assert idx._compile_cache.hits >= 1
 
 
 # -- construction / persistence ------------------------------------------------
