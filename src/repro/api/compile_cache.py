@@ -82,6 +82,10 @@ class CompileCache:
     def __len__(self) -> int:
         return len(self._entries)
 
+    def keys(self) -> list:
+        """The cached keys, least recently used first."""
+        return list(self._entries)
+
     def __contains__(self, key: Hashable) -> bool:
         return key in self._entries
 

@@ -33,6 +33,7 @@ from repro.api.spec import IndexSpec
 from repro.core import engine as eng
 from repro.core import trie_build as tb
 from repro.core.alphabet import pad_queries
+from repro.spans import RECORDER
 
 
 def _to_device(trie: tb.DictTrie, rule_trie: tb.RuleTrie) -> eng.DeviceTrie:
@@ -99,6 +100,23 @@ BUILD_FIELDS = ("kind", "alpha", "cache_k", "compression", "multiterm_gap")
 
 
 @dataclass
+class LookupStats:
+    """Counts of the one-shot lookup path (``_complete_base``), kept for
+    the life of the index; a reader takes the difference of two
+    readings.  ``calls`` numbers the calls to :meth:`complete` (the
+    request id of its spans)."""
+
+    calls: int = 0
+    queries: int = 0         # rows looked up, padding left out
+    retry_rounds: int = 0    # widened re-dispatches for inexact rows
+    retried_rows: int = 0    # rows those rounds carried
+
+    @property
+    def retries_per_1k(self) -> float:
+        return 1000.0 * self.retry_rounds / max(self.queries, 1)
+
+
+@dataclass
 class PreparedCompaction:
     """A compaction ready to hot-swap: the freshly built index plus the
     {string: score} snapshot it was built from (mutations landing after
@@ -128,6 +146,7 @@ class CompletionIndex:
         #: and scheduler slabs compare against it to know when to migrate
         self.epoch = epoch
         self._overlay: eng.DeltaOverlay | None = None
+        self.lookups = LookupStats()
 
     @property
     def kind(self) -> str:
@@ -410,9 +429,10 @@ class CompletionIndex:
         key = ("batch", batch, length, k, cfg)
 
         def factory():
-            return jax.jit(
-                lambda dev, qs, qlens: eng.complete_batch(dev, cfg, qs,
-                                                          qlens, k))
+            def complete_batch(dev, qs, qlens):
+                return eng.complete_batch(dev, cfg, qs, qlens, k)
+
+            return jax.jit(complete_batch)
 
         return partial(self._compile_cache.get(key, factory), self.device)
 
@@ -422,12 +442,18 @@ class CompletionIndex:
 
         def factory():
             cfg = self.cfg
-            init = jax.jit(lambda dev: eng.init_locus_state(dev, cfg))
-            adv = jax.jit(lambda dev, state, c:
-                          eng.advance_locus_state(dev, cfg, state, c))
-            topk = jax.jit(
-                lambda dev, state: eng.topk_from_loci(dev, cfg, state, k))
-            return init, adv, topk
+
+            def session_init(dev):
+                return eng.init_locus_state(dev, cfg)
+
+            def session_advance(dev, state, c):
+                return eng.advance_locus_state(dev, cfg, state, c)
+
+            def session_topk(dev, state):
+                return eng.topk_from_loci(dev, cfg, state, k)
+
+            return tuple(map(jax.jit, (session_init, session_advance,
+                                       session_topk)))
 
         return tuple(partial(f, self.device)
                      for f in self._compile_cache.get(key, factory))
@@ -444,20 +470,18 @@ class CompletionIndex:
 
         def factory():
             cfg = self.cfg
-            init = jax.jit(lambda dev: eng.init_locus_batch(dev, cfg, block))
 
-            def _advance(dev, slab, chars, resets):
-                fresh = eng.init_locus_state(dev, cfg)
-                slab = jax.tree.map(
-                    lambda s, z: jnp.where(
-                        resets.reshape((block,) + (1,) * (s.ndim - 1)),
-                        z, s),
-                    slab, fresh)
-                return eng.advance_loci_batch(dev, cfg, slab, chars)
+            def slab_init(dev):
+                return eng.init_locus_batch(dev, cfg, block)
+
+            def slab_advance(dev, slab, chars, resets):
+                return eng.advance_loci_batch(dev, cfg, slab, chars,
+                                              resets=resets)
 
             # the slab is threaded flush-to-flush and never read after the
             # advance, so donating it lets XLA update lanes in place
-            return init, jax.jit(_advance, donate_argnums=1)
+            return jax.jit(slab_init), jax.jit(slab_advance,
+                                               donate_argnums=1)
 
         return tuple(partial(f, self.device)
                      for f in self._compile_cache.get(key, factory))
@@ -468,10 +492,60 @@ class CompletionIndex:
 
         def factory():
             cfg = self.cfg
-            return jax.jit(
-                lambda dev, slab: eng.topk_from_loci_batch(dev, cfg, slab, k))
+
+            def slab_topk(dev, slab):
+                return eng.topk_from_loci_batch(dev, cfg, slab, k)
+
+            return jax.jit(slab_topk)
 
         return partial(self._compile_cache.get(key, factory), self.device)
+
+    def compiled_programs(self) -> list[tuple[tuple, str]]:
+        """``(cache key, compiled HLO text)`` of every program in the
+        compile cache, compiled again from the key's shapes (where JAX's
+        persistent cache is on, it returns the executable that ran).
+
+        A device trace names each operation by its HLO instruction; the
+        text's ``op_name`` metadata carries the engine phase the
+        instruction belongs to (``engine.walk``, ``engine.advance``,
+        ``engine.topk``)."""
+        def spec(shape, dtype=jnp.int32):
+            return jax.ShapeDtypeStruct(shape, dtype)
+
+        def slab_spec(block):
+            return jax.eval_shape(partial(eng.init_locus_batch, cfg=self.cfg,
+                                          batch=block), self.device)
+
+        out = []
+        for key in self._compile_cache.keys():
+            kind, cfg = key[0], key[-1]
+            if kind == "batch":
+                _, b, n, k, _ = key
+                progs = [(self._fn(b, n, k, cfg), (spec((b, n)), spec((b,))))]
+            elif cfg != self.cfg:
+                continue        # compiled for an earlier configuration
+            elif kind == "slab":
+                block = key[1]
+                init, adv = self._slab_fns(block)
+                progs = [(init, ()),
+                         (adv, (slab_spec(block), spec((block,)),
+                                spec((block,), bool)))]
+            elif kind == "slab_topk":
+                progs = [(self._slab_topk_fn(key[1], key[2]),
+                          (slab_spec(key[1]),))]
+            elif kind == "session":
+                init, adv, topk = self._session_fns(key[1])
+                state = jax.eval_shape(init)
+                progs = [(init, ()), (adv, (state, spec(()))),
+                         (topk, (state,))]
+            else:               # overlay_merge: (B, C, k)
+                _, b, c, k, _ = key
+                progs = [(partial(self._merge_fn(b, c, k)),
+                          (spec((b, c)), spec((b, c))))]
+            for fn, args in progs:
+                text = fn.func.lower(*fn.args, *args).compile().as_text()
+                out.append((key, text))
+        return out
 
     def session(self, k: int = 10):
         """Open a stateful incremental-typing session (see
@@ -495,20 +569,31 @@ class CompletionIndex:
         return self._complete_base(qs, qlens, k)
 
     def _complete_base(self, qs: np.ndarray, qlens: np.ndarray, k: int):
+        rec = RECORDER
+        rid = self.lookups.calls
         B, L = qs.shape
+        self.lookups.queries += B
         Bb, Lb = bucket_size(B, minimum=1), bucket_size(L)
         if (Bb, Lb) != (B, L):
+            sp = rec.begin("index.pad", rid) if rec.on else -1
             qs = np.pad(qs, ((0, Bb - B), (0, Lb - L)), constant_values=-1)
             qlens = np.pad(qlens, (0, Bb - B))
+            if sp >= 0:
+                rec.end(sp)
         cfg = self.cfg
         fn = self._fn(Bb, Lb, k, cfg)
-        scores, sids, exact = jax.tree.map(np.asarray, fn(qs, qlens))
+        out = fn(qs, qlens)
+        sp = rec.begin("index.fetch", rid) if rec.on else -1
+        scores, sids, exact = jax.tree.map(np.asarray, out)
+        if sp >= 0:
+            rec.end(sp)
         bad = ~exact
         bad[B:] = False
         if bad.any():   # np.asarray views of jit output are read-only
             scores, sids = scores.copy(), sids.copy()
         tries = 0
         while bad.any() and tries < 3:
+            sp = rec.begin("index.retry", rid) if rec.on else -1
             # the widened config re-dispatches through the substrate, so
             # each retry round re-probes can_beam_batch: the first round
             # (gens x4) re-enters the fused beam kernel at the default
@@ -517,16 +602,23 @@ class CompletionIndex:
             cfg = replace(cfg, frontier=cfg.frontier * 2, gens=cfg.gens * 4,
                           max_steps=cfg.max_steps * 4, use_cache=False)
             sub = np.nonzero(bad)[0]
+            self.lookups.retry_rounds += 1
+            self.lookups.retried_rows += len(sub)
             Sb = bucket_size(len(sub), minimum=1)
             pad_sub = np.pad(sub, (0, Sb - len(sub)))  # repeat row 0: harmless
             fn2 = self._fn(Sb, Lb, k, cfg)
-            s2, i2, e2 = jax.tree.map(
-                np.asarray, fn2(qs[pad_sub], qlens[pad_sub]))
+            out = fn2(qs[pad_sub], qlens[pad_sub])
+            sf = rec.begin("index.fetch", rid) if sp >= 0 else -1
+            s2, i2, e2 = jax.tree.map(np.asarray, out)
+            if sf >= 0:
+                rec.end(sf)
             scores[sub], sids[sub] = s2[:len(sub)], i2[:len(sub)]
             bad2 = np.zeros_like(bad)
             bad2[sub] = ~e2[:len(sub)]
             bad = bad2
             tries += 1
+            if sp >= 0:
+                rec.end(sp)
         return scores[:B], sids[:B]
 
     def _merge_fn(self, B: int, C: int, k: int):
@@ -536,8 +628,11 @@ class CompletionIndex:
 
         def factory():
             sub = eng.get_substrate(self.cfg.substrate)
-            return jax.jit(
-                lambda s, g: eng.merge_overlay_topk(s, g, k, sub))
+
+            def overlay_merge(s, g):
+                return eng.merge_overlay_topk(s, g, k, sub)
+
+            return jax.jit(overlay_merge)
 
         return self._compile_cache.get(key, factory)
 
@@ -582,13 +677,24 @@ class CompletionIndex:
 
         Returns a list (per query) of (score, suggestion string) pairs.
         """
+        rec = RECORDER
+        self.lookups.calls += 1
+        rid = self.lookups.calls
+        sc = rec.begin("index.complete", rid) if rec.on else -1
+        sp = rec.begin("index.pad", rid) if sc >= 0 else -1
         max_len = max((len(q.encode() if isinstance(q, str) else q)
                        for q in queries), default=1)
         qs, qlens = pad_queries(queries, max(max_len, 1))
+        if sp >= 0:
+            rec.end(sp)
         scores, sids = self.complete_batch_padded(qs, qlens, k)
+        sp = rec.begin("index.decode", rid) if sc >= 0 else -1
         out = []
         for b in range(len(queries)):
             out.append(self._decode_row(scores[b], sids[b]))
+        if sp >= 0:
+            rec.end(sp)
+            rec.end(sc)
         return out
 
     def _decode_row(self, scores, sids) -> list[tuple[int, str]]:

@@ -19,6 +19,12 @@ kernels in :mod:`repro.kernels`; every hot phase is substrate-pluggable.
 The substrate name lives on :class:`EngineConfig` and therefore joins
 every jit/compile-cache key.
 
+Each phase's entry point runs under a ``jax.named_scope`` named after
+its dispatch record (``record_dispatch``): ``engine.walk`` (the one-shot
+locus DP), ``engine.advance`` (one keystroke of a session or slab) and
+``engine.topk`` (phase 2, with the finalize/decode of carried loci), so
+a device trace names the phase of every operation.
+
 Everything here lowers under jit/vmap/shard_map with ShapeDtypeStruct
 inputs, which is what the multi-pod dry-run exercises.
 """

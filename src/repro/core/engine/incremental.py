@@ -63,7 +63,12 @@ def advance_locus_state(t: DeviceTrie, cfg: EngineConfig, state: LocusState,
     whose lhs ends exactly at the new char — but reuses the carried frontier
     instead of rescanning the prefix.
     """
-    sub = resolve_sub(cfg, sub)
+    with jax.named_scope("engine.advance"):
+        return _advance_locus_state(t, cfg, state, c, resolve_sub(cfg, sub))
+
+
+def _advance_locus_state(t: DeviceTrie, cfg: EngineConfig,
+                         state: LocusState, c, sub) -> LocusState:
     note_path("advance", "jnp")   # no substrate has a kernel for it
     F = cfg.frontier
     E = cfg.edit_budget
@@ -167,7 +172,8 @@ def init_locus_batch(t: DeviceTrie, cfg: EngineConfig, batch: int,
 
 
 def advance_loci_batch(t: DeviceTrie, cfg: EngineConfig, states: LocusState,
-                       chars: jax.Array, sub=None) -> LocusState:
+                       chars: jax.Array, sub=None,
+                       resets: jax.Array | None = None) -> LocusState:
     """One keystroke per lane across a stacked state batch.
 
     ``states`` is a LocusState whose leaves carry a leading batch dim;
@@ -175,11 +181,21 @@ def advance_loci_batch(t: DeviceTrie, cfg: EngineConfig, states: LocusState,
     single-state no-op contract of :func:`advance_locus_state`), so a
     partially filled micro-batch block advances only its live lanes in
     one dispatch.  Per-lane results are bit-identical to the sequential
-    :func:`advance_locus_state` — lanes never interact (pure vmap)."""
+    :func:`advance_locus_state` — lanes never interact (pure vmap).
+    Lanes flagged in ``resets`` (bool[B]) restart at the empty prefix
+    before their char."""
     sub = resolve_sub(cfg, sub)
-    return jax.vmap(
-        lambda s, c: advance_locus_state(t, cfg, s, c, sub))(
-        states, jnp.asarray(chars, jnp.int32))
+    with jax.named_scope("engine.advance"):
+        if resets is not None:
+            fresh = init_locus_state(t, cfg, sub)
+            states = jax.tree.map(
+                lambda s, z: jnp.where(
+                    resets.reshape(resets.shape + (1,) * (s.ndim - 1)),
+                    z, s),
+                states, fresh)
+        return jax.vmap(
+            lambda s, c: _advance_locus_state(t, cfg, s, c, sub))(
+            states, jnp.asarray(chars, jnp.int32))
 
 
 def topk_from_loci(t: DeviceTrie, cfg: EngineConfig, state: LocusState,
@@ -188,9 +204,11 @@ def topk_from_loci(t: DeviceTrie, cfg: EngineConfig, state: LocusState,
     from repro.core.engine.substrate import topk_phase2
 
     sub = resolve_sub(cfg, sub)
-    loci = finalize_loci(t, decode_states(state.rows[0], cfg.edit_budget)[0])
-    scores, sids, exact = topk_phase2(t, cfg, loci, k, sub)
-    return scores, sids, exact & (state.overflow == 0)
+    with jax.named_scope("engine.topk"):
+        loci = finalize_loci(
+            t, decode_states(state.rows[0], cfg.edit_budget)[0])
+        scores, sids, exact = topk_phase2(t, cfg, loci, k, sub)
+        return scores, sids, exact & (state.overflow == 0)
 
 
 def topk_from_loci_batch(t: DeviceTrie, cfg: EngineConfig,
@@ -205,7 +223,8 @@ def topk_from_loci_batch(t: DeviceTrie, cfg: EngineConfig,
     from repro.core.engine.substrate import topk_phase2_batch
 
     sub = resolve_sub(cfg, sub)
-    loci = jax.vmap(lambda row: finalize_loci(
-        t, decode_states(row, cfg.edit_budget)[0]))(states.rows[:, 0])
-    scores, sids, exact = topk_phase2_batch(t, cfg, loci, k, sub)
-    return scores, sids, exact & (states.overflow == 0)
+    with jax.named_scope("engine.topk"):
+        loci = jax.vmap(lambda row: finalize_loci(
+            t, decode_states(row, cfg.edit_budget)[0]))(states.rows[:, 0])
+        scores, sids, exact = topk_phase2_batch(t, cfg, loci, k, sub)
+        return scores, sids, exact & (states.overflow == 0)

@@ -509,9 +509,11 @@ def complete_batch(t: DeviceTrie, cfg: EngineConfig, qs: jax.Array,
     """qs: int32[B, L]; qlens: int32[B] -> (scores[B,k], sids[B,k],
     exact[B])."""
     sub = primitives.resolve_sub(cfg, sub)
-    loci, overflow = sub.walk_batch(t, cfg, qs, qlens)
-    scores, sids, exact = _phase2_batch(t, cfg, loci, k, sub)
-    return scores, sids, exact & (overflow == 0)
+    with jax.named_scope("engine.walk"):
+        loci, overflow = sub.walk_batch(t, cfg, qs, qlens)
+    with jax.named_scope("engine.topk"):
+        scores, sids, exact = _phase2_batch(t, cfg, loci, k, sub)
+        return scores, sids, exact & (overflow == 0)
 
 
 def complete_one(t: DeviceTrie, cfg: EngineConfig, q: jax.Array,

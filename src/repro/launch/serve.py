@@ -86,6 +86,7 @@ def serve_autocomplete(spec, args):
         "queries": len(results),
         "us_per_completion": round(dt / max(len(results), 1) * 1e6, 1),
         "hit_rate": round(hit, 3),
+        "retries_per_1k": round(idx.lookups.retries_per_1k, 3),
     }
     print(json.dumps(out))
     return out
@@ -219,6 +220,8 @@ def serve_zipf(spec, args):
         "mean_occupancy": round(bstats.mean_occupancy, 2),
         "deadline_flushes": bstats.deadline_flushes,
         "fallbacks": bstats.fallbacks,
+        "queue_wait_ms": round(bstats.mean_queue_wait_ms, 3),
+        "answer_lag_ms": round(bstats.mean_answer_lag_ms, 3),
     }
     print(json.dumps(out))
     return out

@@ -54,6 +54,7 @@ import numpy as np
 import jax
 
 from repro.api.session import resolve_topk
+from repro.spans import RECORDER
 
 
 class SchedulerOverloaded(RuntimeError):
@@ -76,7 +77,8 @@ class Ticket:
     reset_first: bool = False     # re-init the lane before the char step
     results: list | None = None   # (score, string) pairs once resolved
     done: bool = False
-    latency_s: float | None = None
+    consumed: float | None = None  # start of the flush that took it
+    latency_s: float | None = None  # created -> resolved
 
     def result(self, scheduler: "KeystrokeScheduler") -> list:
         """Block (cooperatively) until this keystroke's flush lands."""
@@ -87,21 +89,39 @@ class Ticket:
 
 @dataclass
 class BatchStats:
-    """Flush accounting for one scheduler."""
+    """Flush accounting for one scheduler.
+
+    The times are sums of the scheduler's clock, so a reader takes the
+    difference of two readings and divides: a keystroke's latency
+    (created -> resolved) is its queue wait (created -> consumed, the
+    start of the flush that took it) plus its answer lag (consumed ->
+    resolved)."""
 
     n_keystrokes: int = 0
     n_flushes: int = 0
     full_flushes: int = 0          # every occupied lane advanced
     deadline_flushes: int = 0      # fired by the latency budget
-    forced_flushes: int = 0        # drain()/result() forced a partial block
     rejected: int = 0              # submits refused by backpressure
     fallbacks: int = 0             # inexact lanes resolved via one-shot path
     sum_occupancy: int = 0         # lanes consumed across all flushes
     migrations: int = 0            # slab rebuilds onto a new index epoch
+    sum_queue_wait_s: float = 0.0  # over every ticket a flush consumed
+    sum_answer_lag_s: float = 0.0  # over result-bearing tickets resolved
+    n_answered: int = 0            # result-bearing tickets resolved
+    dispatch_s: float = 0.0        # host time in the advance/top-k calls
+    settle_wait_s: float = 0.0     # host time blocked in device_get
 
     @property
     def mean_occupancy(self) -> float:
         return self.sum_occupancy / max(self.n_flushes, 1)
+
+    @property
+    def mean_queue_wait_ms(self) -> float:
+        return 1e3 * self.sum_queue_wait_s / max(self.sum_occupancy, 1)
+
+    @property
+    def mean_answer_lag_ms(self) -> float:
+        return 1e3 * self.sum_answer_lag_s / max(self.n_answered, 1)
 
 
 class BatchSession:
@@ -382,12 +402,19 @@ class KeystrokeScheduler:
     def _flush(self, kind: str) -> None:
         if self._epoch != self.index.epoch:
             self._migrate()
+        st = self.stats
+        rec = RECORDER
+        rid = st.n_flushes + 1
+        fl = rec.begin("sched.flush", rid) if rec.on else -1
+        start = self.clock()
         # one ticket per lane, FIFO within the lane
         taken: list[Ticket] = []
         chars = np.full((self.block,), -1, np.int32)
         resets = np.zeros((self.block,), bool)
         for lane in self._ready_lanes():
             t = self._queues[lane].popleft()
+            t.consumed = start
+            st.sum_queue_wait_s += start - t.created
             taken.append(t)
             chars[lane] = t.char
             resets[lane] = t.reset_first
@@ -403,8 +430,12 @@ class KeystrokeScheduler:
                     self._consumed[lane] = b""
                     self._n_occupied -= 1
         self._pending -= len(taken)
+        t0 = self.clock()
         self._slab = self._advance_fn(self._slab, chars, resets)
-        st = self.stats
+        now = self.clock()
+        st.dispatch_s += now - t0
+        if fl >= 0:
+            rec.add("sched.dispatch", t0, now, rid)
         st.n_flushes += 1
         st.sum_occupancy += len(taken)
         st.n_keystrokes += sum(t.char >= 0 for t in taken)
@@ -412,9 +443,6 @@ class KeystrokeScheduler:
             st.full_flushes += 1
         elif kind == "deadline":
             st.deadline_flushes += 1
-        else:
-            st.forced_flushes += 1
-        now = self.clock()
         for t in taken:
             if not t.want_topk:     # advance-only keystrokes resolve here
                 t.done = True
@@ -437,10 +465,18 @@ class KeystrokeScheduler:
                 if topk_fn is None:
                     topk_fn = self._topk_fns[k] = \
                         self.index._slab_topk_fn(self.block, k)
-                stash.append((k, tickets, topk_fn(self._slab)))
+                t0 = self.clock()
+                handles = topk_fn(self._slab)
+                t1 = self.clock()
+                st.dispatch_s += t1 - t0
+                if fl >= 0:
+                    rec.add("sched.dispatch", t0, t1, rid)
+                stash.append((rid, k, tickets, handles))
             self._unsettled = stash
         if prev:
             self._settle_handles(prev)
+        if fl >= 0:
+            rec.end(fl, (kind, len(taken)))
 
     def _migrate(self) -> None:
         """Rebuild the slab on the index's current epoch (hot-swap /
@@ -477,17 +513,34 @@ class KeystrokeScheduler:
             self._settle_handles(prev)
 
     def _settle_handles(self, stash) -> None:
-        # each stashed entry holds the full [block, k] slab result; the
-        # lanes wanting that k are picked out
-        for k, tickets, handles in stash:
+        # each stashed entry holds the full [block, k] slab result of
+        # flush ``rid``; the lanes wanting that k are picked out
+        st = self.stats
+        rec = RECORDER
+        se = rec.begin("sched.settle", stash[0][0]) if rec.on else -1
+        for rid, k, tickets, handles in stash:
+            t0 = self.clock()
             scores, sids, exact = jax.device_get(handles)
+            t1 = self.clock()
+            st.settle_wait_s += t1 - t0
+            rs = -1
+            if se >= 0:
+                rec.add("sched.device_get", t0, t1, rid)
+                rs = rec.begin("sched.resolve", rid)
             for t in tickets:
                 if not bool(exact[t.lane]):
-                    self.stats.fallbacks += 1
+                    st.fallbacks += 1
                 t.results = resolve_topk(
                     self.index, scores[t.lane], sids[t.lane],
                     exact[t.lane], t.prefix, k)
                 t.done = True
-                t.latency_s = self.clock() - t.created
+                now = self.clock()
+                t.latency_s = now - t.created
+                st.sum_answer_lag_s += now - t.consumed
+                st.n_answered += 1
                 if self.on_keystroke is not None:
                     self.on_keystroke(t.latency_s)
+            if rs >= 0:
+                rec.end(rs)
+        if se >= 0:
+            rec.end(se)
