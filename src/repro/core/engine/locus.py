@@ -310,19 +310,24 @@ def locus_dp(t: DeviceTrie, cfg: EngineConfig, q: jax.Array, qlen: jax.Array,
             ar = jnp.where(anchor_ok, nodes, 0)
             anchor_ok &= ~(pk.syn_mask_of(t, ar) if packed else t.syn_mask[ar])
             anchors = jnp.where(anchor_ok, nodes, NEG_ONE)
+            rows = jnp.arange(L + 1, dtype=jnp.int32)
             for m in range(M):
                 rid = mrule[i, m]
                 end = mend[i, m]
                 tgt = link_lookup(t, anchors, rid)
                 tgt = jnp.where((rid >= 0), tgt, NEG_ONE)
                 tgt = encode_states(tgt, d, E)
+                # the destination row differs per query, so read and write
+                # it with a one-hot select over the rows: under vmap a
+                # dynamic slice / update at a per-lane index becomes a
+                # gather / scatter, which XLA:TPU runs one lane at a time
                 j = jnp.clip(jnp.where(end >= 0, end, 0), 0, L)
-                dst = jax.lax.dynamic_slice(buf, (j, 0), (1, F))[0]
+                sel = (rows == j)[:, None]
+                dst = jnp.max(jnp.where(sel, buf, NEG_ONE), axis=0)
                 merged, drop = sub.dedup_compact(jnp.concatenate([dst, tgt]), F)
                 any_tgt = jnp.any(tgt >= 0)
-                merged = jnp.where(any_tgt, merged, dst)
                 overflow += jnp.where(any_tgt, drop, 0)
-                buf = jax.lax.dynamic_update_slice(buf, merged[None], (j, 0))
+                buf = jnp.where(sel & any_tgt, merged[None], buf)
 
         # write-back: row i+1 is complete (rule ends are > i), expand it
         nxt = jax.lax.dynamic_slice(buf, (i + 1, 0), (1, F))[0]

@@ -7,6 +7,7 @@ kernel routed in by the pallas substrate (batched trie walk, topk_select,
 cached locus gather+merge) must reproduce the reference engine exactly.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -215,6 +216,43 @@ def test_fused_walk_overflow_frontier_parity(paper_data, kind):
     idx = _build(paper_data, kind, frontier=1)
     ov = _walk_parity(idx, QUERIES + ["andy", "bill", "bill of ri"], 16)
     assert (ov > 0).any()   # F=1 cannot hold literal node + rule target
+
+
+# two rules share the lhs "andy", so one position has two matches that
+# end at the same frontier row
+SHARED_LHS_RULES = [("andy", "andrew"), ("andy", "andrea"),
+                    ("bill", "william")]
+ROW_EDGE_CASES = {
+    # padded to the query length: the lhs ends at row L, the last one
+    "lhs_ends_at_last_row": (["andy", "bill"], 4),
+    "two_matches_one_row": (["andy pa", "andy sm", "bill of"], 8),
+}
+
+
+@pytest.mark.parametrize("frontier", [32, 1])
+@pytest.mark.parametrize("case", sorted(ROW_EDGE_CASES))
+def test_rule_step_row_edges_parity(paper_data, case, frontier):
+    """The rule steps write each match's targets into the frontier row
+    its lhs ends at, chosen per query.  At the edges of that choice the
+    jnp DP must match the fused kernel bit-for-bit, loci and overflow."""
+    from repro.core.alphabet import pad_queries
+
+    strings, scores, _ = paper_data
+    idx = build_index(strings + ["andrea smith"], scores + [25],
+                      make_rules(SHARED_LHS_RULES),
+                      IndexSpec(kind="tt", frontier=frontier))
+    queries, L = ROW_EDGE_CASES[case]
+    qs, _ = pad_queries(queries, L)
+    rules, ends = (np.asarray(a) for a in jax.vmap(
+        lambda q: eng.match_table(idx.device, idx.cfg, q))(qs))
+    if case == "lhs_ends_at_last_row":
+        assert (ends == L).any()
+    else:   # two matches at one position, both ending on one row
+        assert ((rules[..., 0] >= 0) & (rules[..., 1] >= 0)
+                & (ends[..., 0] == ends[..., 1])).any()
+    ov = _walk_parity(idx, queries, L)
+    # F=1 cannot hold both rules' targets in the row they share
+    assert (ov > 0).any() == (frontier == 1)
 
 
 def test_fused_walk_nonbucket_batches(paper_data):

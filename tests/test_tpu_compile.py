@@ -17,6 +17,7 @@ to turn its xfail into a plain test.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +28,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.api import IndexSpec, build_index
 from repro.core import engine as eng
 from repro.core import make_rules
-from repro.data.strings import make_usps
+from repro.data.strings import make_dblp, make_usps
 from repro.kernels import ops
 
 N_SMALL = 2000          # strings actually built here (tables fit VMEM)
@@ -86,6 +87,14 @@ def et_cached(usps):
     return _index(usps, kind="et", cache_k=16)
 
 
+@pytest.fixture(scope="module")
+def tt_dblp():
+    """The DBLP config's index kind: rule steps in the locus DP."""
+    ds = make_dblp(n=N_SMALL, seed=0)
+    return build_index(ds.strings, ds.scores, make_rules(ds.rules),
+                       IndexSpec(kind="tt"))
+
+
 def _sds(x, sharding, scale: bool = False, name: str = ""):
     shape = tuple(x.shape)
     if scale and name in DICT_FIELDS and shape[0] > 1:
@@ -126,6 +135,23 @@ def test_complete_batch_compiles(which, et, et_cached, one_chip):
                     _arg((B, L), jnp.int32, one_chip),
                     _arg((B,), jnp.int32, one_chip))
     assert "tpu_custom_call" not in text
+
+
+def test_rule_steps_compile_without_per_lane_loops(tt_dblp, one_chip):
+    """Each rule step writes into the frontier row its match ends at,
+    which differs per query.  Under vmap a row update at a per-lane index
+    is a scatter, and XLA:TPU runs it as a loop over the lanes nested in
+    the walk loop: the row is selected lane-parallel instead."""
+    idx = tt_dblp
+    assert idx.cfg.rule_matches > 0
+    text = _compile(idx._fn(B, L, K, idx.cfg).func,
+                    _trie(idx.device, one_chip, full=False),
+                    _arg((B, L), jnp.int32, one_chip),
+                    _arg((B,), jnp.int32, one_chip))
+    loops = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines() if " while(" in line]
+    assert any(name.endswith("engine.walk/vmap()/while") for name in loops)
+    assert not [name for name in loops if name.endswith("scatter")]
 
 
 def test_session_advance_and_topk_compile(et, one_chip):
